@@ -71,6 +71,21 @@ class RoundRecord(NamedTuple):
     contributor over all T iterations), ``bytes_ext`` the BS↔cloud bytes
     (Eq. 5, 2·payload·M), and ``compress_error`` the mean per-transmission
     L2 norm of the error-feedback residual (NaN when compression is off).
+    The GBP-CS work counters (reported by the fused and sharded FEDGS
+    engines; NaN for baselines and the host loop) sum over the round's T
+    iterations: ``gbp_cs_steps`` is the permutation steps GBP-CS took over
+    all groups, ``gbp_cs_step_slots`` the steps its group-vmapped
+    ``while_loop`` executed (groups × the slowest group's steps, since a
+    batched loop runs until its last lane is done); both are 0 on
+    iterations that keep the carried committee or select at random, and
+    their ratio is how much of the batched solve did useful work.
+
+    Profiler names (DESIGN.md §13.3): the round's phases run under
+    ``jax.named_scope`` — ``fedgs.sample``, ``fedgs.schedule``,
+    ``fedgs.select``, ``fedgs.train``, ``fedgs.eq4``, ``fedgs.eq5`` and
+    ``engine.eval`` — which reach the compiled HLO as ``op_name`` metadata
+    only; :func:`records_from_metrics` marks the host's wait for a dispatch
+    and its transfer with ``engine.wait`` and ``engine.fetch`` spans.
     """
     round: int
     loss: float
@@ -92,6 +107,8 @@ class RoundRecord(NamedTuple):
     bytes_int: float = _NAN
     bytes_ext: float = _NAN
     compress_error: float = _NAN
+    gbp_cs_steps: float = _NAN
+    gbp_cs_step_slots: float = _NAN
 
     def to_dict(self) -> dict:
         d = dict(self._asdict())
@@ -107,7 +124,8 @@ _OPTIONAL_METRICS = ("divergence", "group_discrepancy", "selection_distance",
                      "reselections", "participation", "staleness_mean",
                      "staleness_max", "dark_selected", "corrupted_selected",
                      "clipped_fraction", "rollbacks", "agg_residual",
-                     "bytes_int", "bytes_ext", "compress_error")
+                     "bytes_int", "bytes_ext", "compress_error",
+                     "gbp_cs_steps", "gbp_cs_step_slots")
 
 
 def records_from_metrics(r0: int, metrics: dict, *, strategy: str = ""
@@ -116,22 +134,28 @@ def records_from_metrics(r0: int, metrics: dict, *, strategy: str = ""
 
     ``metrics`` maps name -> (chunk,) array; recognized names: ``loss``,
     ``test_loss``, ``test_accuracy`` (NaN = no eval that round), and the
-    telemetry names in ``_OPTIONAL_METRICS``.
+    telemetry names in ``_OPTIONAL_METRICS``. The wait for the dispatch
+    runs under an ``engine.wait`` profiler span, the transfer and the
+    records under ``engine.fetch``.
     """
-    host = {k: np.asarray(v, np.float64) for k, v in metrics.items()}
-    n = len(next(iter(host.values())))
-    recs = []
-    for i in range(n):
-        tl = host.get("test_loss", [_NAN] * n)[i]
-        ta = host.get("test_accuracy", [_NAN] * n)[i]
-        recs.append(RoundRecord(
-            round=r0 + i,
-            loss=float(host["loss"][i]) if "loss" in host else _NAN,
-            test_loss=None if math.isnan(tl) else float(tl),
-            test_accuracy=None if math.isnan(ta) else float(ta),
-            strategy=strategy,
-            **{k: float(host[k][i]) for k in _OPTIONAL_METRICS if k in host},
-        ))
+    with jax.profiler.TraceAnnotation("engine.wait"):
+        jax.block_until_ready(metrics)
+    with jax.profiler.TraceAnnotation("engine.fetch"):
+        host = {k: np.asarray(v, np.float64) for k, v in metrics.items()}
+        n = len(next(iter(host.values())))
+        recs = []
+        for i in range(n):
+            tl = host.get("test_loss", [_NAN] * n)[i]
+            ta = host.get("test_accuracy", [_NAN] * n)[i]
+            recs.append(RoundRecord(
+                round=r0 + i,
+                loss=float(host["loss"][i]) if "loss" in host else _NAN,
+                test_loss=None if math.isnan(tl) else float(tl),
+                test_accuracy=None if math.isnan(ta) else float(ta),
+                strategy=strategy,
+                **{k: float(host[k][i]) for k in _OPTIONAL_METRICS
+                   if k in host},
+            ))
     return recs
 
 
@@ -183,11 +207,12 @@ def _make_chunk_fn(exp: Experiment, eval_every: int, unroll: int):
         metrics = dict(metrics)
         if exp.eval_fn is not None and eval_every > 0:
             nan2 = (jnp.float32(_NAN), jnp.float32(_NAN))
-            tl, ta = jax.lax.cond(
-                (r + 1) % eval_every == 0,
-                lambda p: exp.eval_fn(p),
-                lambda p: nan2,
-                exp.params_fn(state))
+            with jax.named_scope("engine.eval"):
+                tl, ta = jax.lax.cond(
+                    (r + 1) % eval_every == 0,
+                    lambda p: exp.eval_fn(p),
+                    lambda p: nan2,
+                    exp.params_fn(state))
             metrics["test_loss"] = jnp.asarray(tl, jnp.float32)
             metrics["test_accuracy"] = jnp.asarray(ta, jnp.float32)
         return state, metrics

@@ -281,16 +281,18 @@ def _per_group_train(params_m: PyTree, batches_m: PyTree, loss_fn: LossFn,
         weights = jnp.ones((cfg.num_selected,), jnp.float32)
     if cfg.train_step == "model_avg":
         dev_step = lambda b: sync.local_step(params_m, b, loss_fn, cfg.lr)
-        new_params, losses = jax.vmap(dev_step)(batches_m)
-        synced = dispatch.internal_avg_fn(
-            cfg.kernel_backend, force_interpret=cfg.force_interpret)(
-            new_params, weights)
-        # fault tolerance (DESIGN.md §14.3): a group whose whole committee
-        # went dark (all weights 0) keeps its params instead of averaging
-        # toward the 1e-12-denominator zero model
-        total = jnp.sum(weights)
-        synced = jax.tree.map(
-            lambda s, p: jnp.where(total > 0, s, p), synced, params_m)
+        with jax.named_scope("fedgs.train"):
+            new_params, losses = jax.vmap(dev_step)(batches_m)
+        with jax.named_scope("fedgs.eq4"):
+            synced = dispatch.internal_avg_fn(
+                cfg.kernel_backend, force_interpret=cfg.force_interpret)(
+                new_params, weights)
+            # fault tolerance (DESIGN.md §14.3): a group whose whole
+            # committee went dark (all weights 0) keeps its params instead
+            # of averaging toward the 1e-12-denominator zero model
+            total = jnp.sum(weights)
+            synced = jax.tree.map(
+                lambda s, p: jnp.where(total > 0, s, p), synced, params_m)
         return synced, jnp.mean(losses)
     if cfg.kernel_backend == "pallas":
         n_params = sum(leaf.size for leaf in jax.tree.leaves(params_m))
@@ -298,15 +300,19 @@ def _per_group_train(params_m: PyTree, batches_m: PyTree, loss_fn: LossFn,
             "pallas", cfg.num_selected, n_params,
             force_interpret=cfg.force_interpret)
         if route != "jnp":
-            losses, grads = jax.vmap(
-                lambda b: sync.local_grads(params_m, b, loss_fn))(batches_m)
-            g = dispatch.internal_avg_fn(
-                "pallas", force_interpret=cfg.force_interpret)(grads, weights)
-            if grad_tx is not None:
-                g, e, err = grad_tx(g, e, ckey)
-                return (sync.apply_sgd(params_m, g, cfg.lr),
-                        jnp.mean(losses), e, err)
-            return sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses)
+            with jax.named_scope("fedgs.train"):
+                losses, grads = jax.vmap(
+                    lambda b: sync.local_grads(params_m, b, loss_fn))(
+                        batches_m)
+            with jax.named_scope("fedgs.eq4"):
+                g = dispatch.internal_avg_fn(
+                    "pallas", force_interpret=cfg.force_interpret)(
+                        grads, weights)
+                if grad_tx is not None:
+                    g, e, err = grad_tx(g, e, ckey)
+                    return (sync.apply_sgd(params_m, g, cfg.lr),
+                            jnp.mean(losses), e, err)
+                return sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses)
         # route == 'jnp': the kernel would fall back anyway — skip the
         # member-gradient stack and take the fused single-backward below
     wn = weights / jnp.maximum(jnp.sum(weights), 1e-12)
@@ -315,11 +321,15 @@ def _per_group_train(params_m: PyTree, batches_m: PyTree, loss_fn: LossFn,
         losses = jax.vmap(lambda b: loss_fn(p, b))(batches_m)
         return jnp.sum(losses * wn), losses
 
-    (_, losses), g = jax.value_and_grad(weighted_loss, has_aux=True)(params_m)
-    if grad_tx is not None:
-        g, e, err = grad_tx(g, e, ckey)
-        return sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses), e, err
-    return sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses)
+    with jax.named_scope("fedgs.train"):
+        (_, losses), g = jax.value_and_grad(weighted_loss, has_aux=True)(
+            params_m)
+    with jax.named_scope("fedgs.eq4"):
+        if grad_tx is not None:
+            g, e, err = grad_tx(g, e, ckey)
+            return (sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses),
+                    e, err)
+        return sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses)
 
 
 def _per_group_train_avail(params_m: PyTree, batches_m: PyTree,
@@ -352,18 +362,21 @@ def _per_group_train_avail(params_m: PyTree, batches_m: PyTree,
         losses = jax.vmap(lambda b: loss_fn(p, b))(batches_m)
         return jnp.sum(losses * wn), losses
 
-    (_, losses), g_f = jax.value_and_grad(weighted_loss, has_aux=True)(
-        params_m)
-    frac = stale_sum / denom
-    g = jax.tree.map(lambda gf, gp: gf + frac * gp.astype(jnp.float32),
-                     g_f, g_prev)
-    if grad_tx is not None:
-        g, e, err = grad_tx(g, e, ckey)
+    with jax.named_scope("fedgs.train"):
+        (_, losses), g_f = jax.value_and_grad(weighted_loss, has_aux=True)(
+            params_m)
+    with jax.named_scope("fedgs.eq4"):
+        frac = stale_sum / denom
+        g = jax.tree.map(lambda gf, gp: gf + frac * gp.astype(jnp.float32),
+                         g_f, g_prev)
+        if grad_tx is not None:
+            g, e, err = grad_tx(g, e, ckey)
+            g_out = jax.tree.map(lambda gl, gp: gl.astype(gp.dtype), g,
+                                 g_prev)
+            return (sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses),
+                    g_out, e, err)
         g_out = jax.tree.map(lambda gl, gp: gl.astype(gp.dtype), g, g_prev)
-        return (sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses),
-                g_out, e, err)
-    g_out = jax.tree.map(lambda gl, gp: gl.astype(gp.dtype), g, g_prev)
-    return sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses), g_out
+        return sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses), g_out
 
 
 def _train_all_groups(gp: PyTree, batches: PyTree, group_loss_fn, cfg:
@@ -403,24 +416,30 @@ def _train_all_groups(gp: PyTree, batches: PyTree, group_loss_fn, cfg:
         losses = group_loss_fn(p, batches)        # (M, L)
         return jnp.sum(losses * wn), losses
 
-    (_, losses), g = jax.value_and_grad(weighted_loss, has_aux=True)(gp)
-    if stale_sum is None:
+    with jax.named_scope("fedgs.train"):
+        (_, losses), g = jax.value_and_grad(weighted_loss, has_aux=True)(gp)
+    # the Eq. 4 mean is folded into the weighted loss above, so this scope
+    # holds only the blend, the compression and the update
+    with jax.named_scope("fedgs.eq4"):
+        if stale_sum is None:
+            if grad_tx is not None:
+                g, e, err = jax.vmap(grad_tx)(g, e, ckeys)
+                return (sync.apply_sgd(gp, g, cfg.lr),
+                        jnp.mean(losses, axis=-1), e, err)
+            return sync.apply_sgd(gp, g, cfg.lr), jnp.mean(losses, axis=-1)
+        frac = stale_sum / denom                      # (M,)
+        g = jax.tree.map(
+            lambda gf, gpv: gf + frac.reshape((m,) + (1,) * (gf.ndim - 1))
+            * gpv.astype(jnp.float32), g, g_prev)
         if grad_tx is not None:
             g, e, err = jax.vmap(grad_tx)(g, e, ckeys)
+            g_out = jax.tree.map(lambda gl, gpv: gl.astype(gpv.dtype), g,
+                                 g_prev)
             return (sync.apply_sgd(gp, g, cfg.lr), jnp.mean(losses, axis=-1),
-                    e, err)
-        return sync.apply_sgd(gp, g, cfg.lr), jnp.mean(losses, axis=-1)
-    frac = stale_sum / denom                      # (M,)
-    g = jax.tree.map(
-        lambda gf, gpv: gf + frac.reshape((m,) + (1,) * (gf.ndim - 1))
-        * gpv.astype(jnp.float32), g, g_prev)
-    if grad_tx is not None:
-        g, e, err = jax.vmap(grad_tx)(g, e, ckeys)
+                    g_out, e, err)
         g_out = jax.tree.map(lambda gl, gpv: gl.astype(gpv.dtype), g, g_prev)
         return (sync.apply_sgd(gp, g, cfg.lr), jnp.mean(losses, axis=-1),
-                g_out, e, err)
-    g_out = jax.tree.map(lambda gl, gpv: gl.astype(gpv.dtype), g, g_prev)
-    return sync.apply_sgd(gp, g, cfg.lr), jnp.mean(losses, axis=-1), g_out
+                g_out)
 
 
 def _check_group_loss_fn(group_loss_fn, cfg: FedGSConfig, robust: bool
@@ -453,6 +472,7 @@ class AvailStep(NamedTuple):
     stale_max: Array    # (...,)   max staleness of the stale contributors
 
 
+@jax.named_scope("fedgs.schedule")
 def _avail_weights(mask: Array, avail: Array, staleness: Array,
                    cfg: FedGSConfig) -> AvailStep:
     """Split the committee into fresh vs stale for one iteration. ``fresh_w``
@@ -516,43 +536,46 @@ def _per_group_train_robust(params_m: PyTree, batches_m: PyTree,
     aggregation, so the compressor never sees raw corrupted members —
     extending the return with ``(e', err)``.
     """
-    losses, grads = jax.vmap(
-        lambda b: sync.local_grads(params_m, b, loss_fn))(batches_m)
-    if corrupt_fn is not None:
-        grads, hit = corrupt_fn(grads, t, dev_ids)
-    else:
-        hit = jnp.zeros(weights.shape, jnp.float32)
-    finite = sync.member_finite(grads).astype(jnp.float32)
-    flags = sync.member_outlier_flags(grads, cfg.robust_clip)
-    g = agg_fn(grads, weights)
-    if cfg.robust_agg == "mean":
-        residual = jnp.float32(0.0)
-    else:
-        gm = sync.weighted_average(sync._sanitize(grads, finite > 0),
-                                   weights * finite)
-        residual = jnp.sqrt(sum(
-            jnp.sum((a.astype(jnp.float32) - b.astype(jnp.float32)) ** 2)
-            for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gm))))
-    if stale_sum is not None:
-        w_fresh = jnp.sum(weights * finite)
-        denom = jnp.maximum(w_fresh + stale_sum, sync.EPS)
-        g = jax.tree.map(
-            lambda gf, gp: (w_fresh * gf.astype(jnp.float32)
-                            + stale_sum * gp.astype(jnp.float32)) / denom,
-            g, g_prev)
-        if grad_tx is not None:
-            g, e, err = grad_tx(g, e, ckey)
-        g_out = jax.tree.map(lambda gl, gp: gl.astype(gp.dtype), g, g_prev)
-    else:
-        if grad_tx is not None:
-            g, e, err = grad_tx(g, e, ckey)
-        g_out = g
-    step = RobustStep(hit=hit, flags=flags, residual=residual)
+    with jax.named_scope("fedgs.train"):
+        losses, grads = jax.vmap(
+            lambda b: sync.local_grads(params_m, b, loss_fn))(batches_m)
+    with jax.named_scope("fedgs.schedule"):
+        if corrupt_fn is not None:
+            grads, hit = corrupt_fn(grads, t, dev_ids)
+        else:
+            hit = jnp.zeros(weights.shape, jnp.float32)
+    with jax.named_scope("fedgs.eq4"):
+        finite = sync.member_finite(grads).astype(jnp.float32)
+        flags = sync.member_outlier_flags(grads, cfg.robust_clip)
+        g = agg_fn(grads, weights)
+        if cfg.robust_agg == "mean":
+            residual = jnp.float32(0.0)
+        else:
+            gm = sync.weighted_average(sync._sanitize(grads, finite > 0),
+                                       weights * finite)
+            residual = jnp.sqrt(sum(
+                jnp.sum((a.astype(jnp.float32) - b.astype(jnp.float32)) ** 2)
+                for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gm))))
+        if stale_sum is not None:
+            w_fresh = jnp.sum(weights * finite)
+            denom = jnp.maximum(w_fresh + stale_sum, sync.EPS)
+            g = jax.tree.map(
+                lambda gf, gp: (w_fresh * gf.astype(jnp.float32)
+                                + stale_sum * gp.astype(jnp.float32)) / denom,
+                g, g_prev)
+            if grad_tx is not None:
+                g, e, err = grad_tx(g, e, ckey)
+            g_out = jax.tree.map(lambda gl, gp: gl.astype(gp.dtype), g,
+                                 g_prev)
+        else:
+            if grad_tx is not None:
+                g, e, err = grad_tx(g, e, ckey)
+            g_out = g
+        step = RobustStep(hit=hit, flags=flags, residual=residual)
+        params_new = sync.apply_sgd(params_m, g, cfg.lr)
     if grad_tx is not None:
-        return (sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses), g_out,
-                step, e, err)
-    return (sync.apply_sgd(params_m, g, cfg.lr), jnp.mean(losses), g_out,
-            step)
+        return params_new, jnp.mean(losses), g_out, step, e, err
+    return params_new, jnp.mean(losses), g_out, step
 
 
 def _group_finite(tree: PyTree) -> Array:
@@ -1302,69 +1325,79 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
         def iteration(carry, t):
             gp, key, sel = carry
             mask, dist = sel[0], sel[1]
-            # PRNG discipline identical to the host loop: split the round
-            # key, fan out to all M groups, take this shard's slice.
-            key, sub = jax.random.split(key)
-            keys = jnp.take(jax.random.split(sub, m), gids, axis=0)
+            with jax.named_scope("fedgs.select"):
+                # PRNG discipline identical to the host loop: split the
+                # round key, fan out to all M groups, take this shard's
+                # slice.
+                key, sub = jax.random.split(key)
+                keys = jnp.take(jax.random.split(sub, m), gids, axis=0)
             if spec_int is not None:
                 # side-chained like the fault/availability streams: fold_in
                 # off the round sub-key so the selection PRNG chain is
                 # untouched, then the global-fan-out/take slice keeps the
                 # stochastic rounding invariant to the shard count
-                csub = jax.random.fold_in(sub, compress.FOLD_COMPRESS)
-                ckeys = jnp.take(jax.random.split(csub, m), gids, axis=0)
+                with jax.named_scope("fedgs.eq4"):
+                    csub = jax.random.fold_in(sub, compress.FOLD_COMPRESS)
+                    ckeys = jnp.take(jax.random.split(csub, m), gids, axis=0)
             e_int = sel[i_eint] if spec_int is not None else None
-            counts = sampler.counts(t, gids)
-            # Resident ids (DESIGN.md §17): schedules evaluate on the (G, K)
-            # flat POPULATION ids of the devices seated this iteration — the
-            # sampler's `device_ids` when it draws from a larger universe
-            # (lazy population / candidate subsampling), else the historical
-            # dense gid·K+slot grid (bit-identical values). Built only when a
-            # schedule or the robust path consumes them.
-            if avail_fn is not None or robust:
-                if ids_fn is None:
-                    dev_ids_all = gids[:, None] * k + jnp.arange(
-                        k, dtype=jnp.int32)
-                else:
-                    dev_ids_all = ids_fn(t, gids).astype(jnp.int32)
+            with jax.named_scope("fedgs.sample"):
+                counts = sampler.counts(t, gids)
+                # Resident ids (DESIGN.md §17): schedules evaluate on the
+                # (G, K) flat POPULATION ids of the devices seated this
+                # iteration — the sampler's `device_ids` when it draws from
+                # a larger universe (lazy population / candidate
+                # subsampling), else the historical dense gid·K+slot grid
+                # (bit-identical values). Built only when a schedule or the
+                # robust path consumes them.
+                if avail_fn is not None or robust:
+                    if ids_fn is None:
+                        dev_ids_all = gids[:, None] * k + jnp.arange(
+                            k, dtype=jnp.int32)
+                    else:
+                        dev_ids_all = ids_fn(t, gids).astype(jnp.int32)
             if avail_fn is None:
                 avail = None
             else:
-                up, _lat = avail_fn(t, dev_ids_all.reshape(-1))
-                avail = up.reshape((gids.shape[0], k))
+                with jax.named_scope("fedgs.schedule"):
+                    up, _lat = avail_fn(t, dev_ids_all.reshape(-1))
+                    avail = up.reshape((gids.shape[0], k))
             sel_avail = avail if cfg.avail_selection == "aware" else None
             quar = sel[-1] if quarantined else None
-            if quarantined:
-                # repeat gradient offenders are barred from selection like
-                # dark devices (DESIGN.md §15.4)
-                ok = selection.quarantine_mask(quar, cfg.quarantine_limit)
-                sel_avail = ok if sel_avail is None else sel_avail * ok
-            if cfg.reselect_every == 1:
-                res = selection.select_for_groups(
-                    keys, counts, p_real, l, cfg.num_presampled,
-                    avail=sel_avail, method=cfg.selection, init=cfg.init,
-                    max_iters=cfg.gbp_max_iters,
-                    step_fn=dispatch.gbp_step_fn(cfg.kernel_backend))
-                mask, div, dist = res.mask, res.divergence, res.distance
-                resel = jnp.float32(1.0)
-            else:
-                do = selection.reselect_predicate(t, cfg.reselect_every)
-                if sel_avail is not None and not bounded:
-                    # churn re-trigger (DESIGN.md §14.2) — psum'd so every
-                    # shard takes the same lax.cond branch
-                    dark_under = selection.reselect_trigger(
-                        do, mask, sel_avail, l)
-                    do = dark_under if mesh is None else \
-                        jax.lax.psum(dark_under.astype(jnp.float32),
-                                     axis_name) > 0
-                mask, div, dist = selection.select_or_keep(
-                    do, keys, counts, p_real, l, cfg.num_presampled,
-                    prev_mask=mask, prev_distance=dist, avail=sel_avail,
-                    method=cfg.selection, init=cfg.init,
-                    max_iters=cfg.gbp_max_iters,
-                    step_fn=dispatch.gbp_step_fn(cfg.kernel_backend))
-                resel = do.astype(jnp.float32)
-            imgs, labs = sampler.selected_batch(t, gids, mask, l)
+            with jax.named_scope("fedgs.select"):
+                if quarantined:
+                    # repeat gradient offenders are barred from selection
+                    # like dark devices (DESIGN.md §15.4)
+                    ok = selection.quarantine_mask(quar, cfg.quarantine_limit)
+                    sel_avail = ok if sel_avail is None else sel_avail * ok
+                if cfg.reselect_every == 1:
+                    res = selection.select_for_groups(
+                        keys, counts, p_real, l, cfg.num_presampled,
+                        avail=sel_avail, method=cfg.selection, init=cfg.init,
+                        max_iters=cfg.gbp_max_iters,
+                        step_fn=dispatch.gbp_step_fn(cfg.kernel_backend))
+                    mask, div, dist = res.mask, res.divergence, res.distance
+                    steps = res.iterations
+                    resel = jnp.float32(1.0)
+                else:
+                    do = selection.reselect_predicate(t, cfg.reselect_every)
+                    if sel_avail is not None and not bounded:
+                        # churn re-trigger (DESIGN.md §14.2) — psum'd so
+                        # every shard takes the same lax.cond branch
+                        dark_under = selection.reselect_trigger(
+                            do, mask, sel_avail, l)
+                        do = dark_under if mesh is None else \
+                            jax.lax.psum(dark_under.astype(jnp.float32),
+                                         axis_name) > 0
+                    mask, div, dist, steps = selection.select_or_keep(
+                        do, keys, counts, p_real, l, cfg.num_presampled,
+                        prev_mask=mask, prev_distance=dist, avail=sel_avail,
+                        method=cfg.selection, init=cfg.init,
+                        max_iters=cfg.gbp_max_iters,
+                        step_fn=dispatch.gbp_step_fn(cfg.kernel_backend))
+                    resel = do.astype(jnp.float32)
+                steps, slots = selection.step_counts(steps)
+            with jax.named_scope("fedgs.sample"):
+                imgs, labs = sampler.selected_batch(t, gids, mask, l)
             extra = {}
             if robust:
                 # corruption-exposed path (DESIGN.md §15): materialized
@@ -1378,7 +1411,9 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
                     st = _avail_weights(mask, avail, sel[2], cfg)
                     fresh_w = st.fresh_w
                 else:
-                    fresh_w = vals * jnp.take_along_axis(avail, idx, axis=-1)
+                    with jax.named_scope("fedgs.schedule"):
+                        fresh_w = vals * jnp.take_along_axis(avail, idx,
+                                                             axis=-1)
                 gp_old = gp
                 if bounded:
                     g_prev_old = sel[3]
@@ -1418,28 +1453,32 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
                         )(gp, (imgs, labs), fresh_w, dev_ids)
                 rollbacks = jnp.float32(0.0)
                 if guard:
-                    finite_m = _group_finite(gp)
-                    if bounded:
-                        finite_m = finite_m & _group_finite(g_prev)
-                    if grad_tx is not None:
-                        # a poisoned residual would re-inject the fault next
-                        # iteration via error feedback — roll it back with
-                        # the group (DESIGN.md §18.1)
-                        finite_m = finite_m & _group_finite(e_int)
-                    gp = _where_groups(finite_m, gp, gp_old)
-                    if bounded:
-                        g_prev = _where_groups(finite_m, g_prev, g_prev_old)
-                        staleness = jnp.where(finite_m[:, None],
-                                              staleness, sel[2])
-                    if grad_tx is not None:
-                        e_int = _where_groups(finite_m, e_int, e_old)
-                    rollbacks = jnp.sum(1.0 - finite_m.astype(jnp.float32))
+                    with jax.named_scope("fedgs.eq4"):
+                        finite_m = _group_finite(gp)
+                        if bounded:
+                            finite_m = finite_m & _group_finite(g_prev)
+                        if grad_tx is not None:
+                            # a poisoned residual would re-inject the fault
+                            # next iteration via error feedback — roll it
+                            # back with the group (DESIGN.md §18.1)
+                            finite_m = finite_m & _group_finite(e_int)
+                        gp = _where_groups(finite_m, gp, gp_old)
+                        if bounded:
+                            g_prev = _where_groups(finite_m, g_prev,
+                                                   g_prev_old)
+                            staleness = jnp.where(finite_m[:, None],
+                                                  staleness, sel[2])
+                        if grad_tx is not None:
+                            e_int = _where_groups(finite_m, e_int, e_old)
+                        rollbacks = jnp.sum(
+                            1.0 - finite_m.astype(jnp.float32))
                 sel_new = (mask, dist, staleness, g_prev) if bounded \
                     else (mask, dist)
                 if quarantined:
-                    quar_new = jax.vmap(
-                        lambda q, i, f: q.at[i].add(f.astype(jnp.int32))
-                    )(quar, idx, rs.flags * vals)
+                    with jax.named_scope("fedgs.eq4"):
+                        quar_new = jax.vmap(
+                            lambda q, i, f: q.at[i].add(f.astype(jnp.int32))
+                        )(quar, idx, rs.flags * vals)
                 uploads = jnp.sum((fresh_w > 0).astype(jnp.float32))
                 seated = jnp.sum(vals)
                 extra = {"corrupted_selected": jnp.sum(rs.hit * vals),
@@ -1510,8 +1549,9 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
                          "staleness_mean": jnp.mean(st.stale_mean),
                          "staleness_max": jnp.max(st.stale_max)}
             else:
-                vals, idx = jax.lax.top_k(mask, l)
-                fresh_w = vals * jnp.take_along_axis(avail, idx, axis=-1)
+                with jax.named_scope("fedgs.schedule"):
+                    vals, idx = jax.lax.top_k(mask, l)
+                    fresh_w = vals * jnp.take_along_axis(avail, idx, axis=-1)
                 if grad_tx is not None:
                     if grouped:
                         gp, losses, e_int, cerr = _train_all_groups(
@@ -1550,6 +1590,7 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
             # the dense path too, so FedAvg-vs-FedGS byte ledgers always
             # compare like for like
             extra["bytes_int"] = 2.0 * payload_int * uploads
+            extra["gbp_cs_steps"], extra["gbp_cs_step_slots"] = steps, slots
             disc = jnp.mean(distributions.group_discrepancy(counts, p_real))
             loss, div, d = jnp.mean(losses), jnp.mean(div), jnp.mean(dist)
             if mesh is not None:
@@ -1563,7 +1604,8 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
                     if name in extra:
                         extra[name] = jax.lax.pmean(extra[name], axis_name)
                 for name in ("dark_selected", "corrupted_selected",
-                             "rollbacks", "bytes_int"):
+                             "rollbacks", "bytes_int", "gbp_cs_steps",
+                             "gbp_cs_step_slots"):
                     if name in extra:
                         extra[name] = jax.lax.psum(extra[name], axis_name)
                 if "staleness_max" in extra:
@@ -1583,12 +1625,13 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
             # (group_params — every row identical), with per-group error
             # feedback carried in the selection state at i_eext. Key split
             # only on this path so the 'none' chain stays untouched.
-            key, esub = jax.random.split(key)
-            ekeys = jnp.take(jax.random.split(esub, m), gids, axis=0)
-            gp, e_ext, err_ext = _external_compress(
-                group_params, gp, sel[i_eext], ekeys, spec_ext,
-                backend=cfg.kernel_backend,
-                force_interpret=cfg.force_interpret)
+            with jax.named_scope("fedgs.eq5"):
+                key, esub = jax.random.split(key)
+                ekeys = jnp.take(jax.random.split(esub, m), gids, axis=0)
+                gp, e_ext, err_ext = _external_compress(
+                    group_params, gp, sel[i_eext], ekeys, spec_ext,
+                    backend=cfg.kernel_backend,
+                    force_interpret=cfg.force_interpret)
             sel = sel[:i_eext] + (e_ext,) + sel[i_eext + 1:]
             err_ext_m = jnp.mean(err_ext)
             if mesh is not None:
@@ -1598,13 +1641,14 @@ def make_round_body(loss_fn: LossFn, cfg: FedGSConfig, sampler, *,
         # base stations (static — Eq. 5 always moves the full payload)
         mets["bytes_ext"] = jnp.float32(2.0 * payload_ext * m)
         # epilogue: external sync (Eq. 5) + broadcast back to the group axis
-        g = sync.external_sync_grouped(
-            gp, axis_name if mesh is not None else None,
-            mean_fn=dispatch.external_avg_fn(
-                cfg.kernel_backend, force_interpret=cfg.force_interpret))
-        gp = jax.tree.map(
-            lambda leaf: jnp.broadcast_to(leaf[None],
-                                          (m_local,) + leaf.shape), g)
+        with jax.named_scope("fedgs.eq5"):
+            g = sync.external_sync_grouped(
+                gp, axis_name if mesh is not None else None,
+                mean_fn=dispatch.external_avg_fn(
+                    cfg.kernel_backend, force_interpret=cfg.force_interpret))
+            gp = jax.tree.map(
+                lambda leaf: jnp.broadcast_to(leaf[None],
+                                              (m_local,) + leaf.shape), g)
         return gp, key, sel, mets
 
     return round_body
@@ -1706,6 +1750,10 @@ def make_fedgs_experiment(
             out["clipped_fraction"] = jnp.mean(mets["clipped_fraction"])
             out["rollbacks"] = jnp.sum(mets["rollbacks"])
             out["agg_residual"] = jnp.mean(mets["agg_residual"])
+        # GBP-CS work counters: permutation steps taken, and the steps the
+        # vmapped while_loop executed (groups x the slowest group's steps)
+        out["gbp_cs_steps"] = jnp.sum(mets["gbp_cs_steps"])
+        out["gbp_cs_step_slots"] = jnp.sum(mets["gbp_cs_step_slots"])
         # §18.3 byte ledger — always emitted (dense numbers when
         # compression is off) so crossover sweeps compare like for like
         out["bytes_int"] = jnp.sum(mets["bytes_int"])

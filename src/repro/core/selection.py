@@ -149,6 +149,16 @@ select_groups_any = functools.partial(
 )(select_for_groups)
 
 
+def step_counts(iterations: Array) -> tuple[Array, Array]:
+    """GBP-CS work of one selection over M groups, from the per-group
+    permutation steps ``iterations`` (M,): ``(steps, slots)`` as float32,
+    where ``steps`` is their sum and ``slots`` the steps the vmapped
+    ``while_loop`` executed — M times the largest group's steps, since a
+    batched loop runs until its last lane is done."""
+    it = jnp.asarray(iterations, jnp.float32)
+    return jnp.sum(it), it.shape[0] * jnp.max(it)
+
+
 def quarantine_mask(quarantine: Array, limit: int) -> Array:
     """Selection eligibility from per-device quarantine counters
     (DESIGN.md §15.4): a device flagged as a gradient outlier ``limit`` or
@@ -201,7 +211,7 @@ def select_or_keep(do_reselect: Array, keys: Array, counts: Array,
                    avail: Array | None = None,
                    method: str = "gbp_cs", init: str = gbp_cs.MPINV,
                    max_iters: int = 64, step_fn=None
-                   ) -> tuple[Array, Array, Array]:
+                   ) -> tuple[Array, Array, Array, Array]:
     """Periodic in-scan reselection: run GBP-CS for all M groups, or keep
     the carried masks, behind ONE scalar ``lax.cond`` (DESIGN.md §13).
 
@@ -219,19 +229,21 @@ def select_or_keep(do_reselect: Array, keys: Array, counts: Array,
     ``sync`` mode :func:`reselect_trigger` folds churn into ``do_reselect``
     so this cond rebuilds instead of keeping).
 
-    Returns ``(mask (M, K), divergence (M,), distance (M,))``; distance is
-    the GBP-CS objective of the LAST rebuild (carried through skips).
+    Returns ``(mask (M, K), divergence (M,), distance (M,), iterations
+    (M,))``; distance is the GBP-CS objective of the LAST rebuild (carried
+    through skips), iterations the permutation steps taken (0 on a keep).
     """
 
     def fresh(_):
         sel = select_for_groups(keys, counts, p_real, l, l_rnd, avail=avail,
                                 method=method, init=init,
                                 max_iters=max_iters, step_fn=step_fn)
-        return sel.mask, sel.divergence, sel.distance
+        return sel.mask, sel.divergence, sel.distance, sel.iterations
 
     def keep(_):
         c = counts if avail is None else counts * avail[..., None]
         div = mask_divergence(c, prev_mask, p_real)
-        return prev_mask, div, prev_distance
+        return (prev_mask, div, prev_distance,
+                jnp.zeros(prev_distance.shape, jnp.int32))
 
     return jax.lax.cond(do_reselect, fresh, keep, None)

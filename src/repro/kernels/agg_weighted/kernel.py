@@ -35,4 +35,5 @@ def agg_weighted_kernel(stacked: jax.Array, weights: jax.Array, *,
         out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, p), jnp.float32),
         interpret=interpret,
+        name="agg_weighted",
     )(weights[None], stacked)[0]

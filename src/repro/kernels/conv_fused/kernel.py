@@ -77,4 +77,5 @@ def conv_fused_kernel(patches: jax.Array, w: jax.Array, bias: jax.Array, *,
             jax.ShapeDtypeStruct((g, *lead, rp, cp), jnp.float32),
         ],
         interpret=interpret,
+        name="conv_fused",
     )(patches, w, bias)

@@ -111,6 +111,7 @@ def residual(A: jax.Array, x: jax.Array, y: jax.Array, *, bk: int = 128,
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="gbp_cs",
     )(x[None], A, y[:, None])
     return r[:, 0], d2[0, 0]
 
@@ -132,5 +133,6 @@ def select_swap(A: jax.Array, x: jax.Array, r: jax.Array, *, k_valid: int,
         out_specs=pl.BlockSpec((1, LANES), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, LANES), jnp.float32),
         interpret=interpret,
+        name="gbp_cs",
     )(r[:, None], A, x[None])
     return best[0, 1].astype(jnp.int32), best[0, 3].astype(jnp.int32)

@@ -79,4 +79,5 @@ def robust_agg_kernel(stacked: jax.Array, active: jax.Array, *,
         out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, p), jnp.float32),
         interpret=interpret,
+        name="robust_agg",
     )(active.astype(jnp.float32)[:, None], stacked)[0]

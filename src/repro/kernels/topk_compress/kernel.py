@@ -66,4 +66,5 @@ def topk_select_kernel(x: jax.Array, *, k: int, block_p: int = 512,
         out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, p), jnp.float32),
         interpret=interpret,
+        name="topk_compress",
     )(x[None], x[None])[0]

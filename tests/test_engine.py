@@ -194,7 +194,7 @@ def test_round_record_typed_log():
                       "staleness_mean", "staleness_max", "dark_selected",
                       "corrupted_selected", "clipped_fraction", "rollbacks",
                       "agg_residual", "bytes_int", "bytes_ext",
-                      "compress_error"}
+                      "compress_error", "gbp_cs_steps", "gbp_cs_step_slots"}
     # NaN telemetry slots (strategies without them) -> None, JSON-safe
     assert d["group_discrepancy"] is None and d["reselections"] is None
     assert d["participation"] is None and d["staleness_max"] is None

@@ -12,6 +12,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,17 +80,21 @@ def _robust(method):
     return build
 
 
-@pytest.mark.parametrize("build", [
-    _gbp_cs, _agg_weighted, _conv(28, 1, 32), _conv(14, 32, 64),
-    _robust("coord_median"), _robust("trimmed_mean"),
+@pytest.mark.parametrize("build,name", [
+    (_gbp_cs, "gbp_cs"), (_agg_weighted, "agg_weighted"),
+    (_conv(28, 1, 32), "conv_fused"), (_conv(14, 32, 64), "conv_fused"),
+    (_robust("coord_median"), "robust_agg"),
+    (_robust("trimmed_mean"), "robust_agg"),
 ], ids=["gbp_cs", "agg_weighted", "conv_fused_layer1", "conv_fused_layer2",
         "robust_agg_coord_median", "robust_agg_trimmed_mean"])
-def test_kernel_compiles_for_v5e(build, one_chip):
+def test_kernel_compiles_for_v5e(build, name, one_chip):
     fn, shapes = build()
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name is its instruction's, and so its trace event's
+    assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text)
 
 
 def test_topk_compress_refuses_sizes_past_the_chip_limit():
