@@ -132,7 +132,18 @@ def writer_style_table(writer_ids: np.ndarray) -> np.ndarray:
 
 def _affine_sample_jax(protos, classes, rots, scales, shifts):
     """jax port of :func:`_affine_sample`: bilinear sampling under per-sample
-    inverse affine transforms. classes (N,), rots/scales (N,), shifts (N, 2)."""
+    inverse affine transforms. classes (N,), rots/scales (N,), shifts (N, 2).
+
+    The four bilinear taps are not gathered. Inside the image, bilinear
+    sampling at a source point (sx, sy) is the separable tent contraction
+    ``sum_y sum_x img[y, x] * relu(1 - |sy - y|) * relu(1 - |sx - x|)``, so
+    each image is one ``(S, S) @ (S, P)`` matmul with its column tents and a
+    multiply-reduce with its row tents; the ``oob`` mask zeroes what falls
+    outside, as in the numpy sampler. An accelerator runs a per-pixel gather
+    about one index at a time, and a matmul on its matrix unit. The matmul
+    runs at HIGHEST precision: the images are float32, as the gathers were.
+    """
+    import jax
     import jax.numpy as jnp
 
     n = classes.shape[0]
@@ -148,18 +159,11 @@ def _affine_sample_jax(protos, classes, rots, scales, shifts):
     src = jnp.einsum("nij,jp->nip", rot_m, xy) * inv_scale[:, None, None]
     src = src + c0 - shifts[:, :, None]                           # (n,2,P)
     sx, sy = src[:, 0], src[:, 1]
-    x0 = jnp.clip(jnp.floor(sx).astype(jnp.int32), 0, size - 2)
-    y0 = jnp.clip(jnp.floor(sy).astype(jnp.int32), 0, size - 2)
-    fx = jnp.clip(sx - x0, 0, 1).astype(jnp.float32)
-    fy = jnp.clip(sy - y0, 0, 1).astype(jnp.float32)
-    flat = protos[classes].reshape(n, -1)                         # (n, P)
-    idx = lambda yv, xv: yv * size + xv
-    g00 = jnp.take_along_axis(flat, idx(y0, x0), axis=1)
-    g01 = jnp.take_along_axis(flat, idx(y0, x0 + 1), axis=1)
-    g10 = jnp.take_along_axis(flat, idx(y0 + 1, x0), axis=1)
-    g11 = jnp.take_along_axis(flat, idx(y0 + 1, x0 + 1), axis=1)
-    out = (g00 * (1 - fx) * (1 - fy) + g01 * fx * (1 - fy)
-           + g10 * (1 - fx) * fy + g11 * fx * fy)
+    grid = jnp.arange(size, dtype=jnp.float32)[:, None]           # (S, 1)
+    tent = lambda s: jax.nn.relu(1 - jnp.abs(s[:, None, :] - grid))
+    rows = jnp.einsum("nyx,nxp->nyp", protos[classes], tent(sx),
+                      precision=jax.lax.Precision.HIGHEST)        # (n,S,P)
+    out = (rows * tent(sy)).sum(axis=1)                           # (n, P)
     oob = (sx < 0) | (sx > size - 1) | (sy < 0) | (sy > size - 1)
     out = jnp.where(oob, 0.0, out)
     return out.reshape(n, size, size).astype(jnp.float32)
