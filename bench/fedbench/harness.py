@@ -14,7 +14,6 @@ import collections
 import dataclasses
 import functools
 import gc
-import importlib.util
 import json
 import math
 import os
@@ -23,20 +22,13 @@ import sys
 import tempfile
 import time
 
-from . import compare, spec, trace as trace_lib, yardstick
+from . import compare, phases, spec, trace as trace_lib, yardstick
 
 TRACED_ROUNDS = 2          # a round boundary falls inside the trace
 
 
 class NoChip(RuntimeError):
     pass
-
-
-def _load_file(path: str, name: str):
-    mod_spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod
 
 
 def _program_on_path() -> None:
@@ -157,9 +149,11 @@ def start_program(cell: dict, seed: int) -> ProgramRun:
     args = train.parse_args(spec.train_flags(config, cell["traffic"], seed))
     world = train.build_world(args)
     n_par = sum(leaf.size for leaf in jax.tree.leaves(world.params))
-    if n_par != yardstick.param_count(config["model"]):
-        raise ValueError(f"the program's CNN has {n_par} parameters, the "
-                         f"configuration {yardstick.param_count(config['model'])}")
+    want = cell["counts"].param_count(config)
+    if n_par != want:
+        raise ValueError(f"the program's model has {n_par} parameters, the "
+                         f"configuration's counts ({config['counts']}) "
+                         f"{want}")
     exp = train.build_fedgs_experiment(args, world, None)
     run = ProgramRun(
         exp=exp, dispatch=engine._make_chunk_fn(exp, 1, 1),
@@ -179,8 +173,8 @@ def reference_rounds(cell: dict, seed: int, rounds: int, *,
     bfloat16; otherwise under full float32 matmul precision."""
     import jax
     import jax.numpy as jnp
-    mod = _load_file(spec.reference_path(cell["config"]),
-                     "fedbench_reference")
+    mod = spec.load_module(spec.reference_path(cell["config"]),
+                           "fedbench_reference")
     ref = mod.Reference(cell["config"], cell["traffic"], seed,
                         dtype=jnp.bfloat16 if control else jnp.float32,
                         half_batch=half_batch)
@@ -195,15 +189,34 @@ def _finite(rec: dict) -> bool:
                for k in ("loss", "test_loss", "test_accuracy"))
 
 
-def _read_per_layer(cell: dict, tr, device: dict) -> dict:
-    config = cell["config"]
-    ctx = {"trace": tr, "rounds": TRACED_ROUNDS, "model": config["model"],
-           "deployment": config["deployment"],
-           "peak": yardstick.peak(device["kind"])}
+def round_text(run: ProgramRun) -> str:
+    """The compiled round's HLO text, with each instruction's ``op_name``
+    (the persistent cache holds the program, so this loads it)."""
+    import jax.numpy as jnp
+    return run.dispatch.lower(
+        run.state, run.next_round + jnp.arange(1, dtype=jnp.int32)
+    ).compile().as_text()
+
+
+def reader_context(cell: dict, tr, hlo_text: str, device_kind: str) -> dict:
+    """What every per-layer reader is given: the trace and the number of
+    rounds in it; the compiled round's instruction name -> ``op_name``
+    path (``op_names``) and -> phase (``scopes``); the cell's
+    configuration, traffic and counts module; the chip's peaks."""
+    return {"trace": tr, "rounds": TRACED_ROUNDS,
+            "op_names": phases.op_names(hlo_text),
+            "scopes": phases.op_scopes(hlo_text),
+            "config": cell["config"], "traffic": cell["traffic"],
+            "counts": cell["counts"], "peak": yardstick.peak(device_kind)}
+
+
+def read_per_layer(cell: dict, ctx: dict) -> dict:
+    """name -> {"value", "unit"} of each of the cell's per-layer metrics
+    whose reader finds something to read."""
     out = {}
     for m in cell["per_layer"]:
-        reader = _load_file(spec.metric_path(m["name"]),
-                            "metric_" + m["name"].replace(".", "_"))
+        reader = spec.load_module(spec.metric_path(m["name"]),
+                                  "metric_" + m["name"].replace(".", "_"))
         value = reader.read(ctx)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
@@ -228,6 +241,7 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool, t_start:
 
     window_recs, tr = [], None
     if traced:
+        hlo_text = round_text(run)
         tmp = tempfile.mkdtemp(prefix="fedbench-trace-")
         try:
             jax.profiler.start_trace(tmp)
@@ -258,20 +272,22 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool, t_start:
     attempted = len(window_recs)
     failed = sum(not _finite(r) for r in window_recs)
 
-    metrics = {}
+    metrics, phase_ms = {}, None
     if traced:
         if tr.ops:
             device["busy_s"] = trace_lib.busy_s(tr)
             device["window_s"] = tr.window_s
-            metrics = _read_per_layer(cell, tr, device)
+            ctx = reader_context(cell, tr, hlo_text, device["kind"])
+            metrics = read_per_layer(cell, ctx)
+            phase_ms = {k: 1e3 * v / TRACED_ROUNDS for k, v in
+                        phases.phase_times(tr, ctx["scopes"]).items()}
     else:
         rate = attempted * run.samples_per_round / window_s
         metrics["samples_per_s"] = {"value": rate, "unit": "samples/s"}
         if device["platform"] == "tpu":
-            peak = yardstick.peak(device["kind"])
-            mfu = (yardstick.train_flops_per_sample(cell["config"]["model"])
-                   * rate / (chips * peak["flops_per_s"]))
-            metrics["mfu"] = {"value": 100.0 * mfu, "unit": "%"}
+            metrics["mfu"] = {"value": yardstick.mfu_percent(
+                cell["counts"], cell["config"], cell["traffic"], rate, chips,
+                yardstick.peak(device["kind"])["flops_per_s"]), "unit": "%"}
         if device["memory_peak_bytes"] is not None:
             metrics["peak_hbm_gb"] = {
                 "value": device["memory_peak_bytes"] / 1e9, "unit": "GB"}
@@ -292,6 +308,8 @@ def measure(cell: dict, seed: int, seconds: float, traced: bool, t_start:
         result["breakdown"] = {"device_ops": trace_lib.top_ops(tr),
                                "idle_gaps": trace_lib.idle_gaps(tr)}
     result["setup"] = setup
+    if phase_ms is not None:
+        result["phases_ms"] = phase_ms
     result["readings"] = values
     result["checks"] = checks
     return result

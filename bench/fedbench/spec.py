@@ -1,9 +1,11 @@
 """Cells by name: ``BENCHMARK.json`` at the checkout root names each cell's
 configuration and traffic mix; their files, the per-layer metric readers
 and the limits of each cell's comparison sit under ``bench/`` and are found
-by those names."""
+by those names. A configuration names the modules beside it that hold its
+plain reference (``reference``) and its work counts (``counts``)."""
 from __future__ import annotations
 
+import importlib.util
 import json
 import os
 
@@ -14,6 +16,14 @@ ROOT = os.path.dirname(BENCH)
 def _load(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(path: str, name: str):
+    """The Python file at ``path``, imported as module ``name``."""
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 def benchmark() -> dict:
@@ -29,13 +39,16 @@ def _entry(items: list, name: str, kind: str) -> dict:
 
 def cell(name: str) -> dict:
     """Everything one cell runs with: its entry, its configuration (the
-    file's contents), its traffic mix, its limits and its metrics."""
+    file's contents) with its counts module, its traffic mix, its limits
+    and its metrics."""
     bench = benchmark()
     work = _entry(bench["workloads"], name, "workload")
     conf = _entry(bench["configs"], work["config"], "config")
+    config = _load(os.path.join(ROOT, conf["file"]))
     return {
         "workload": work,
-        "config": _load(os.path.join(ROOT, conf["file"])),
+        "config": config,
+        "counts": counts(config),
         "traffic": traffic(work["traffic"]),
         "limits": limits(name),
         "end_to_end": [m for m in bench["end_to_end"]
@@ -60,6 +73,21 @@ def metric_path(name: str) -> str:
 
 def reference_path(config: dict) -> str:
     return os.path.join(BENCH, "configs", f"{config['reference']}.py")
+
+
+def counts_path(config: dict) -> str:
+    if "counts" not in config:
+        raise KeyError("the configuration names no counts module (key "
+                       "'counts', a file under bench/configs/)")
+    return os.path.join(BENCH, "configs", f"{config['counts']}.py")
+
+
+def counts(config: dict):
+    """The configuration's counts module: ``param_count(config)``,
+    ``train_flops_per_sample(config, traffic)`` and ``SMOKE``, the toy
+    ``model`` and ``flags`` the CPU tests run its cells with."""
+    return load_module(counts_path(config), "fedbench_counts_"
+                       + config["counts"])
 
 
 def train_flags(config: dict, traffic_mix: dict, seed: int) -> list[str]:

@@ -1,5 +1,7 @@
-"""Peaks of the chips and the work a FedGS round requires, counted from
-shapes. Nothing here reads the program."""
+"""Peaks of the chips and the work a FedGS round requires. What a sample
+of one model costs is counted by that configuration's counts module
+(``bench/configs/<counts>.py``, named by the configuration's ``counts``
+key). Nothing here reads the program."""
 from __future__ import annotations
 
 # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
@@ -17,37 +19,15 @@ def peak(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def _layers(model: dict) -> list[tuple[str, int, int, int]]:
-    """(kind, output positions, contraction, outputs) per layer of the
-    paper's CNN: two SAME 5x5 convolutions, each before a 2x2 pool, then
-    two dense layers."""
-    s, k = model["image_size"], model["kernel"]
-    c1, c2 = model["channels"]
-    flat = (s // 4) ** 2 * c2
-    return [("conv", s * s, k * k, c1),
-            ("conv", (s // 2) ** 2, k * k * c1, c2),
-            ("dense", 1, flat, model["hidden"]),
-            ("dense", 1, model["hidden"], model["num_classes"])]
-
-
-def forward_flops_per_sample(model: dict) -> int:
-    return sum(2 * pos * q * c for _, pos, q, c in _layers(model))
-
-
-def train_flops_per_sample(model: dict) -> int:
-    """Forward, weight gradients (as many FLOP as the forward) and input
-    gradients of every layer but the first, whose input is the image.
-    Nothing recomputed, no eval, selection or aggregation."""
-    per = [2 * pos * q * c for _, pos, q, c in _layers(model)]
-    return 2 * sum(per) + sum(per[1:])
-
-
-def param_count(model: dict) -> int:
-    return sum(q * c + c for _, _, q, c in _layers(model))
-
-
 def samples_per_round(deployment: dict) -> int:
-    """M·L·n·T: the images the seated committees train on in one round."""
+    """M·L·n·T: the samples the seated committees train on in one round."""
     d = deployment
     return d["groups"] * d["selected"] * d["batch_size"] * d["iters"]
 
+
+def mfu_percent(counts, config: dict, traffic: dict, samples_per_s: float,
+                chips: int, flops_per_s: float) -> float:
+    """Training FLOP per sample (the configuration's ``counts``) times
+    samples per second, over the chips' peak, in %."""
+    return (100.0 * counts.train_flops_per_sample(config, traffic)
+            * samples_per_s / (chips * flops_per_s))

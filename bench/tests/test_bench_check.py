@@ -33,8 +33,8 @@ def test_reference_lowers_to_one_program_for_every_seed(workload):
     import jax
     import jax.numpy as jnp
     cell = tiny(workload)
-    mod = harness._load_file(spec.reference_path(cell["config"]),
-                             "fedbench_reference")
+    mod = spec.load_module(spec.reference_path(cell["config"]),
+                           "fedbench_reference")
     texts = set()
     for seed in (SEED, 7):
         ref = mod.Reference(cell["config"], cell["traffic"], seed)

@@ -25,24 +25,25 @@ def unchanged_state(monkeypatch):
 
 @pytest.fixture
 def half_batch(monkeypatch):
-    """Every member's loss is the mean over the first half of its batch."""
+    """Every member's loss is the mean over the first half of its batch:
+    the sampler hands each member its batch's first half twice (arrays of
+    shape (groups, members, batch, ...)), whatever the model."""
+    import jax
+    import jax.numpy as jnp
     from repro.launch import train
-    from repro.models import cnn
-    member, grouped = cnn.loss_fn, train.fedgs_group_loss_fn
+    make = train.fedgs_sampler
 
-    def member_half(p, batch):
-        x, y = batch
-        return member(p, (x[: x.shape[0] // 2], y[: y.shape[0] // 2]))
+    def halved(*a, **kw):
+        sampler = make(*a, **kw)
 
-    def grouped_half(args):
-        fn = grouped(args)
-        if fn is None:
-            return None
-        n = args.batch_size // 2
-        return lambda gp, b: fn(gp, (b[0][:, :, :n], b[1][:, :, :n]))
+        def selected_batch(*b):
+            def twice(x):
+                h = x[:, :, : x.shape[2] // 2]
+                return jnp.concatenate([h, h], axis=2)
+            return jax.tree.map(twice, sampler.selected_batch(*b))
+        return sampler._replace(selected_batch=selected_batch)
 
-    monkeypatch.setattr(cnn, "loss_fn", member_half)
-    monkeypatch.setattr(train, "fedgs_group_loss_fn", grouped_half)
+    monkeypatch.setattr(train, "fedgs_sampler", halved)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)

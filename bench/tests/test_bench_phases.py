@@ -4,7 +4,7 @@ scopes in each cell's compiled round."""
 import pytest
 
 from tiny_cell import SEED, WORKLOADS, tiny
-from fedbench import phases, spec, trace
+from fedbench import harness, phases, spec, trace
 
 BODY = "jit(run_chunk)/while/body/"
 # a compiled module's instructions with their op_name metadata (None: the
@@ -81,6 +81,49 @@ def test_without_scopes_everything_is_unscoped():
     assert got[phases.UNSCOPED] == pytest.approx(trace.busy_s(tr))
     assert sum(got.values()) == got[phases.UNSCOPED]
     assert trace.busy_s(tr) == pytest.approx(5.25 - 0.25 - 0.5)
+
+
+def test_scope_time_holds_every_op_under_the_scope():
+    """Unlike a phase, a scope counts every op whose path holds it, the
+    innermost or not: ``fedgs.train`` holds the schedule op run under it."""
+    tr, names = _synthetic_trace(), phases.op_names(_module_text())
+    assert "copy.4" not in names
+    assert names["fusion.3"] == \
+        BODY + "transpose(jvp(fedgs.train))/dot_general"
+    assert phases.scope_time_s(tr, names, "fedgs.train") == \
+        pytest.approx(2.0)
+    assert phases.scope_time_s(tr, names, "fedgs.schedule") == \
+        pytest.approx(0.5)
+    assert phases.scope_time_s(tr, names, "engine.eval") == \
+        pytest.approx(0.5)
+    assert phases.scope_time_s(tr, names, "moe.dispatch") == 0.0
+
+
+# per traced round (2 in the context) of the synthetic trace, in ms
+READ_MS = {"train.ms": 750.0, "sample.ms": 500.0, "eq4.ms": 125.0,
+           "unscoped.ms": 375.0, "select.ms": None}
+
+
+@pytest.mark.parametrize("metric", sorted(READ_MS))
+def test_phase_readers_on_a_synthetic_trace(metric):
+    """Each per-phase reader reads its phase's self time per round from
+    the context the harness gives it; a phase the compiled round does
+    not name (here ``fedgs.select``) reads nothing, and so does every
+    reader of a round compiled without scopes."""
+    tr, text = _synthetic_trace(), _module_text()
+    cell = spec.cell(WORKLOADS[0])
+    reader = spec.load_module(spec.metric_path(metric), "m")
+    ctx = harness.reader_context(cell, tr, text, "TPU v5 lite")
+    assert ctx["rounds"] == 2
+    got = reader.read(ctx)
+    assert got == (None if READ_MS[metric] is None
+                   else pytest.approx(READ_MS[metric]))
+    bare = harness.reader_context(cell, tr, "ENTRY %m {}", "TPU v5 lite")
+    assert reader.read(bare) is None
+    # the phases and unscoped add up to the busy time per round
+    total = sum(phases.phase_ms_per_round(ctx, p) or 0.0
+                for p in phases.PHASES + (phases.UNSCOPED,))
+    assert total == pytest.approx(1e3 * trace.busy_s(tr) / 2)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)

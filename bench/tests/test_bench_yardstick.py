@@ -1,7 +1,7 @@
-"""The benchmark's yardstick on the CPU: FLOP and parameter counts, the
-peak table, the trace reduction on a synthetic trace, name resolution of
-every file ``BENCHMARK.json`` points at, and the refusal to run without a
-TPU."""
+"""The benchmark's yardstick on the CPU: the CNN's FLOP and parameter
+counts, the peak table and the MFU formula, the trace reduction on a
+synthetic trace, name resolution of every file ``BENCHMARK.json`` points
+at, and the refusal to run without a TPU."""
 import json
 import os
 import shutil
@@ -14,20 +14,42 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
-from fedbench import harness, spec, trace, yardstick  # noqa: E402
+from fedbench import spec, trace, yardstick  # noqa: E402
 
-PAPER_CNN = {"image_size": 28, "channels": [32, 64], "kernel": 5,
-             "hidden": 2048, "num_classes": 62}
+PAPER_CNN = {"model": {"image_size": 28, "channels": [32, 64], "kernel": 5,
+                       "hidden": 2048, "num_classes": 62},
+             "counts": "fedgs_cnn_counts"}
 
 
 def test_cnn_flops_and_params():
+    cnn = spec.counts(PAPER_CNN)
     # conv1 1,254,400 + conv2 20,070,400 + fc1 12,845,056 + fc2 253,952
-    assert yardstick.forward_flops_per_sample(PAPER_CNN) == 34_423_808
+    assert cnn.forward_flops_per_sample(PAPER_CNN) == 34_423_808
     # forward + weight gradients + input gradients of all but conv1
-    assert yardstick.train_flops_per_sample(PAPER_CNN) == 102_017_024
-    assert yardstick.param_count(PAPER_CNN) == 6_603_710
+    assert cnn.train_flops_per_sample(PAPER_CNN, {}) == 102_017_024
+    assert cnn.param_count(PAPER_CNN) == 6_603_710
     paper = {"groups": 10, "selected": 10, "batch_size": 32, "iters": 50}
     assert yardstick.samples_per_round(paper) == 160_000
+
+
+def test_mfu_of_the_cnn_cells():
+    """Both CNN configurations count 102,017,024 FLOP per sample, whatever
+    the traffic: 24,988.38 samples/s on one v5e is 1.29403 % of its peak."""
+    peak = yardstick.peak("TPU v5 lite")["flops_per_s"]
+    for work in spec.benchmark()["workloads"]:
+        cell = spec.cell(work["name"])
+        if cell["config"]["counts"] != "fedgs_cnn_counts":
+            continue
+        got = yardstick.mfu_percent(cell["counts"], cell["config"],
+                                    cell["traffic"], 24_988.38, 1, peak)
+        assert got == pytest.approx(100 * 102_017_024 * 24_988.38 / 197e12)
+        assert got == pytest.approx(1.29403, rel=1e-5)
+
+
+def test_a_configuration_without_counts_is_refused():
+    config = {k: v for k, v in PAPER_CNN.items() if k != "counts"}
+    with pytest.raises(KeyError, match="names no counts module"):
+        spec.counts(config)
 
 
 def test_peak_table():
@@ -94,13 +116,18 @@ def test_every_name_in_the_benchmark_resolves():
         assert os.path.isfile(path), path
         assert conf["file"].startswith(tuple(p + "/" for p in bench["paths"]))
     for m in bench["per_layer"]:
-        reader = harness._load_file(spec.metric_path(m["name"]), "m")
+        reader = spec.load_module(spec.metric_path(m["name"]), "m")
         assert callable(reader.read), m["name"]
     for work in bench["workloads"]:
         cell = spec.cell(work["name"])
         assert os.path.isfile(spec.reference_path(cell["config"]))
         assert cell["limits"], work["name"]
-        assert yardstick.param_count(cell["config"]["model"]) == 6_603_710
+        counts = cell["counts"]
+        assert counts.__file__ == spec.counts_path(cell["config"])
+        assert callable(counts.train_flops_per_sample)
+        assert {"model", "flags"} <= set(counts.SMOKE)
+        if cell["config"]["counts"] == "fedgs_cnn_counts":
+            assert counts.param_count(cell["config"]) == 6_603_710
         flags = spec.train_flags(cell["config"], cell["traffic"], 7)
         assert "--seed=7" in flags and "--engine=fused" in flags
         names = {m["name"] for m in cell["end_to_end"]}

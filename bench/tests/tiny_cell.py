@@ -16,16 +16,17 @@ SEED = 2_147_483_659
 
 
 def tiny(workload: str) -> dict:
-    """The cell at toy sizes: the smoke CNN, 2 factories of 6 devices, 3
-    seated, 3 iterations of 4 images; drift epochs and availability
-    dwell shortened so both act inside the two checked rounds."""
+    """The cell at toy sizes: the toy model and flags of its counts
+    module (``SMOKE``), 2 factories of 6 devices, 3 seated, 3 iterations
+    of 4 samples; drift epochs and availability dwell shortened so both
+    act inside the two checked rounds."""
     cell = spec.cell(workload)
     c = copy.deepcopy(cell["config"])
-    c["model"] = {"image_size": 28, "channels": [8, 16], "kernel": 5,
-                  "hidden": 128, "num_classes": 62}
+    smoke = copy.deepcopy(cell["counts"].SMOKE)
+    c["model"] = smoke["model"]
     c["deployment"].update(groups=2, devices_per_group=6, selected=3,
                            presampled=1, iters=3, batch_size=4, lr=0.05)
-    c["flags"] = ["--smoke-model"]
+    c["flags"] = list(c.get("flags", [])) + smoke["flags"]
     cell["config"] = c
     t = copy.deepcopy(cell["traffic"])
     if "drift" in t:
